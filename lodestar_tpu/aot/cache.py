@@ -34,28 +34,16 @@ DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 DEFAULT_MIN_COMPILE_SECS = 1.0
 
 
-def cache_generation() -> str:
-    """The operator-bumpable cache-generation salt (ROADMAP item 3).
-
-    jax never rewrites a cache key whose entry exists-but-fails-to-load,
-    so a poisoned entry under the OLD key survives recompiles forever.
-    The spy's quarantine path (below) heals that in-process; the salt is
-    the out-of-band hammer: bump ``LODESTAR_TPU_CACHE_GENERATION`` once
-    and every program re-warms into a fresh ``gen-<salt>`` subdirectory
-    while the old entries stay untouched on disk (never delete
-    ``.jax_cache``).  The salt is also mixed into the trace-replay cache
-    key (ops/bls12_381/opcache._env_key) so nothing in the process
-    straddles generations."""
-    return os.environ.get("LODESTAR_TPU_CACHE_GENERATION", "").strip()
+# jax reads this variable itself when it starts; where it is set, the
+# process's cache is placed from outside and nothing here moves it
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def repo_cache_dir() -> str:
-    """The effective persistent-cache dir (override: LODESTAR_TPU_JAX_CACHE;
-    salted into a ``gen-<salt>`` subdir when LODESTAR_TPU_CACHE_GENERATION
-    is set — see cache_generation)."""
-    base = os.environ.get("LODESTAR_TPU_JAX_CACHE", DEFAULT_CACHE_DIR)
-    gen = cache_generation()
-    return os.path.join(base, f"gen-{gen}") if gen else base
+    """The persistent-cache dir in effect: ``$JAX_COMPILATION_CACHE_DIR``
+    as given, else the fixed ``<checkout>/.jax_cache`` (the directory
+    is part of what a cached entry is found by, so it never moves)."""
+    return os.environ.get(CACHE_DIR_ENV) or DEFAULT_CACHE_DIR
 
 
 def configure(
@@ -66,7 +54,9 @@ def configure(
     """Point jax at the persistent compilation cache.  Idempotent; safe
     before or after backend init (changing the directory mid-process
     resets jax's internal cache handle, which otherwise keeps serving
-    the OLD directory).  Returns the cache dir in effect."""
+    the OLD directory).  With no ``cache_dir`` and
+    ``$JAX_COMPILATION_CACHE_DIR`` set, jax's own setting from that
+    variable is left alone.  Returns the cache dir in effect."""
     import jax
 
     if os.environ.get("XLA_FLAGS"):
@@ -82,12 +72,14 @@ def configure(
             "not match `python -m lodestar_tpu.aot warm` (which clears "
             "it) — warmed programs may recompile cold"
         )
-    cache_dir = cache_dir or repo_cache_dir()
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", min_compile_time_secs
     )
+    if cache_dir is None and os.environ.get(CACHE_DIR_ENV):
+        return os.environ[CACHE_DIR_ENV]
+    cache_dir = cache_dir or DEFAULT_CACHE_DIR
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     if prev is not None and prev != cache_dir:
         from jax._src import compilation_cache as _cc
 

@@ -15,10 +15,9 @@ exactly the manifest — never the cache files themselves.  Nothing here
 ever deletes ``.jax_cache`` entries; stale entries are merely
 recompiled under their new keys.
 
-Where the running jax supports ``jax.export``, each warmed program is
-additionally serialized to ``<cache>/export/<kernel>_b<bucket>.bin``
-(portable StableHLO, usable for cross-process AOT loading); failures
-are recorded, not fatal.
+Each warmed program is additionally serialized to
+``<cache>/export/<kernel>_b<bucket>.bin`` (portable StableHLO, usable
+for cross-process AOT loading); failures are recorded, not fatal.
 """
 from __future__ import annotations
 
@@ -165,11 +164,9 @@ def program_state(
 
 
 def _try_export(prog, cache_dir: str) -> Tuple[Optional[str], Optional[str]]:
-    """Serialize via jax.export where supported; (path, error)."""
-    try:
-        from jax import export as jexport
-    except ImportError:  # old jax: no export API
-        return None, "jax.export unavailable"
+    """Serialize via jax.export; (path, error)."""
+    from jax import export as jexport
+
     try:
         exported = jexport.export(prog.fn())(*prog.example_args())
         blob = exported.serialize()

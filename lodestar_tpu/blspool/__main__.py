@@ -26,12 +26,25 @@ def build_inner_verifier(choice: str):
     return SingleThreadBlsVerifier()
 
 
-def main(argv=None) -> int:
-    import asyncio
-
+def build_http_server(verifier: str = "auto", tenant_quota=None):
+    """The sidecar ``serve`` runs: the inner verifier behind the
+    multi-tenant server, bound to HTTP (not yet listening)."""
     from .http import BlsPoolHttpServer
     from .metrics import BlsPoolSidecarMetrics
     from .server import DEFAULT_TENANT_QUOTA, BlsPoolServer
+
+    server = BlsPoolServer(
+        build_inner_verifier(verifier),
+        metrics=BlsPoolSidecarMetrics.get(),
+        tenant_quota=tenant_quota or DEFAULT_TENANT_QUOTA,
+    )
+    return BlsPoolHttpServer(server)
+
+
+def main(argv=None) -> int:
+    import asyncio
+
+    from .server import DEFAULT_TENANT_QUOTA
 
     parser = argparse.ArgumentParser(prog="python -m lodestar_tpu.blspool")
     sub = parser.add_subparsers(dest="command")
@@ -54,12 +67,9 @@ def main(argv=None) -> int:
         parser.print_help()
         return 2
 
-    server = BlsPoolServer(
-        build_inner_verifier(args.verifier),
-        metrics=BlsPoolSidecarMetrics.get(),
-        tenant_quota=(args.tenant_quota, args.tenant_quota_ms),
+    http = build_http_server(
+        args.verifier, (args.tenant_quota, args.tenant_quota_ms)
     )
-    http = BlsPoolHttpServer(server)
 
     async def run():
         url = await http.start(args.host, args.port)

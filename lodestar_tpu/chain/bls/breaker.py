@@ -2,8 +2,8 @@
 
 The degradation ladder (chain/bls/device_pool.py) keeps individual
 verification packs alive through transient device faults; the breaker
-handles the *persistent* fault — a wedged XLA runtime, a dead TPU
-tunnel — where every device dispatch costs a multi-second failure
+handles the *persistent* fault — a wedged XLA runtime, a lost
+device — where every device dispatch costs a multi-second failure
 before the fallback runs.  After ``failure_threshold`` CONSECUTIVE
 device failures the breaker opens and the pool routes packs straight to
 the host verifier; after an exponential backoff it half-opens and

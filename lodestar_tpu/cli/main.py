@@ -227,19 +227,14 @@ def resolve_verifier_choice(choice: str) -> str:
     'oracle'.  A TPU-native node defaults to its device path (VERDICT r3
     weak #5: the reverse default made every unflagged run unusable at
     gossip rates); hosts without an accelerator (tests, CI, laptops)
-    still get a working node."""
+    still get a working node.  Only a backend that starts and reports
+    ``cpu`` resolves to the oracle: one that fails to start raises, so a
+    broken chip is never served by the host in silence."""
     if choice != "auto":
         return choice
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() in ("tpu", "gpu"):
-            return "device"
-    # an unusable/missing accelerator backend IS the probe's "oracle"
-    # answer — nothing to surface
-    except Exception:  # lodelint: disable=silent-except
-        pass
-    return "oracle"
+    return "device" if jax.default_backend() in ("tpu", "gpu") else "oracle"
 
 
 def load_jwt_secret(path: str) -> bytes:
@@ -313,8 +308,9 @@ def run_dev(args) -> int:
     verify = args.verifier != "none"
     if args.verifier == "device":
         from lodestar_tpu.chain.bls import DeviceBlsVerifier
+        from lodestar_tpu.chain.bls.metrics import BlsPoolMetrics
 
-        verifier = DeviceBlsVerifier()
+        verifier = DeviceBlsVerifier(metrics=BlsPoolMetrics.get())
 
     slot = 0
     try:

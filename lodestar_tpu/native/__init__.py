@@ -9,11 +9,13 @@ with the system compiler the first time it's needed and bound via ctypes
 
 Every consumer keeps a pure-Python fallback: `available()` gates use, and
 LODESTAR_TPU_NO_NATIVE=1 disables the native path entirely (useful for
-differential tests of the fallbacks).
+differential tests of the fallbacks).  A build or load that fails is
+logged as a warning with the compiler's error, never passed over.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import sys
@@ -28,20 +30,24 @@ _SRCS = [
 _SRC_DEPS = _SRCS + [os.path.join(_HERE, "csrc", "bls_h2c_constants.h")]
 _LIB_PATH = os.path.join(_HERE, f"_lodestar_native_{sys.platform}.so")
 
+_log = logging.getLogger(__name__)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Compile the library; returns None on success, else why not."""
     cc = os.environ.get("CC", "cc")
     cmd = [cc, "-O3", "-shared", "-fPIC", "-fvisibility=hidden",
            "-o", _LIB_PATH, *_SRCS]
     try:
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    return proc.returncode == 0 and os.path.exists(_LIB_PATH)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
+    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
+        return f"{cc} rc={proc.returncode}: {proc.stderr.decode()[-2000:]}"
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -98,10 +104,19 @@ def _load() -> Optional[ctypes.CDLL]:
                 os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)
                 for src in _SRC_DEPS
             ):
-                if not _build():
+                err = _build()
+                if err is not None:
+                    _log.warning(
+                        "native library build failed, pure-Python "
+                        "fallbacks in use: %s", err,
+                    )
                     return None
             _lib = _bind(ctypes.CDLL(_LIB_PATH))
-        except OSError:
+        except OSError as e:
+            _log.warning(
+                "native library failed to load, pure-Python fallbacks "
+                "in use: %s", e,
+            )
             _lib = None
     return _lib
 

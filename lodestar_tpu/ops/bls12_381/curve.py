@@ -159,7 +159,7 @@ def scalar_mul_bits(F: FieldOps, pt, bits):
         acc = pt_select(F, bit != 0, acc_plus, acc)
         return acc, None
 
-    acc0 = inf_like(F, pt)
+    acc0 = fp.vary_like(inf_like(F, pt), pt, bits)
     acc, _ = jax.lax.scan(body, acc0, bits_s)
     return acc
 

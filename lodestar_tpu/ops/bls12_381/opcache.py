@@ -27,19 +27,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax >= 0.5 keeps eval_jaxpr in _src.core; fall back to jax.core
-    from jax._src.core import eval_jaxpr as _eval_jaxpr
-except ImportError:  # pragma: no cover
-    from jax.core import eval_jaxpr as _eval_jaxpr
+from jax._src.core import eval_jaxpr as _eval_jaxpr
 
 _CACHE: dict = {}
-
-
-def _cache_generation() -> str:
-    from lodestar_tpu.aot.cache import cache_generation
-
-    return cache_generation()
 
 
 def _env_key():
@@ -51,12 +41,6 @@ def _env_key():
         fp._target_platform(),
         fp._use_pallas(),
         os.environ.get("LODESTAR_TPU_CPU_PARALLEL_FP"),
-        # cache-generation salt: a generation bump must invalidate
-        # EVERY cached program artifact in the process, traced jaxprs
-        # included, so no replay straddles the old and new persistent-
-        # cache generations.  The shared helper normalizes the value
-        # exactly like the persistent-cache dir does.
-        _cache_generation(),
     )
 
 
@@ -84,6 +68,11 @@ def cached(fn, static_argnums: tuple = ()):
             if av is None:  # non-array leaf (None, python scalar): bypass
                 return fn(*args)
             avals.append(av)
+        if any(jax.typeof(leaf).vma for leaf in leaves):
+            # inside a checked shard_map: the replayed jaxpr was traced
+            # without varying axes, so its scans would mistype the
+            # carries; trace the op itself instead
+            return fn(*args)
         try:
             key = (fn, statics, treedef, tuple(avals), _env_key())
             hash(key)

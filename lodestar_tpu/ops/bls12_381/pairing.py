@@ -101,8 +101,8 @@ def miller_loop(q_aff, p_aff):
     xq, yq = q_aff
     xp, yp = p_aff
     one2 = (jnp.broadcast_to(fp.one_mont(), xq[0].shape), jnp.zeros_like(xq[0]))
-    t0 = (xq, yq, one2)
-    f0 = tw.f12_one(shape=xp.shape[:-1])
+    t0 = fp.vary_like((xq, yq, one2), q_aff, p_aff)
+    f0 = fp.vary_like(tw.f12_one(shape=xp.shape[:-1]), q_aff, p_aff)
     bits = jnp.asarray(_X_BITS)
 
     def body(carry, bit):
@@ -130,7 +130,7 @@ def miller_loop(q_aff, p_aff):
 def _cyclotomic_pow_abs_x(a):
     """a^|x| by square-and-multiply over the static 64-bit parameter."""
     bits = jnp.asarray(np.array([int(b) for b in bin(ABS_X)[2:]], dtype=np.uint32))
-    one = tw.f12_one(shape=jax.tree.leaves(a)[0].shape[:-1])
+    one = fp.vary_like(tw.f12_one(shape=jax.tree.leaves(a)[0].shape[:-1]), a)
 
     def body(acc, bit):
         acc = tw.f12_sqr(acc)

@@ -6,12 +6,13 @@ item 3's architecture step).
 The SURVEY's §2.5/§7 ICI mapping: the signature-set batch axis is
 sharded over the mesh's ``sp`` axis (data parallelism over signature
 sets), each device computes its local r_i·sig_i partial sum and its
-local Miller-loop product, the partials ride the ICI via ``all_gather``,
-and one shared final exponentiation finishes the pairing check.  The
-GSPMD formulation (annotate shardings, let XLA insert the collectives)
-lives in ``__graft_entry__.dryrun_multichip``; THIS module is the
-explicit-axes twin kept for real-hardware bringup, where reading the
-collectives off the program text matters more than compile time.
+local Miller-loop product, the partials ride the ICI in one ``psum``
+each (a gather into per-device slots), and one shared final
+exponentiation finishes the pairing check.  The GSPMD formulation
+(annotate shardings, let XLA insert the collectives) lives in
+``__graft_entry__.dryrun_multichip``; THIS module is the explicit-axes
+twin kept for real-hardware bringup, where reading the collectives off
+the program text matters more than compile time.
 
 Geometry contract (checked statically by lodelint's ``shard-divisibility``
 and dynamically by ``tests/test_mesh_smoke.py``): every bucket in
@@ -41,31 +42,30 @@ SUPPORTED_MESH_SIZES = (2, 4, 8)
 SHARDED_BUCKETS = (128, 512, 1024, 2048)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable ``shard_map``: new jax exposes ``jax.shard_map``
-    with a ``check_vma`` kwarg; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the same check named
-    ``check_rep``.  One adapter so the production formulation (and the
-    lint contract on it) is written once against the new spelling."""
+def _slot_stack(tree, n_dev: int):
+    """Each leaf placed in this device's slot of an ``(n_dev, ...)``
+    zero stack.  A ``psum`` of the stacks is an all_gather whose result
+    jax types as replicated (every other slot adds zero, so it is
+    exact), which is what lets ``out_specs=P()`` pass ``check_vma``:
+    a plain ``all_gather`` output stays typed as varying.
+
+    @mesh: sp
+    """
     import jax
+    import jax.numpy as jnp
 
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        return new(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _old
-
-    return _old(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    i = jax.lax.axis_index(SHARD_AXIS)
+    return jax.tree.map(
+        lambda x: jnp.zeros((n_dev, *x.shape), x.dtype).at[i].set(x), tree
     )
 
 
 def build_sharded_verify(mesh):
     """Manual-collectives batched signature-set verification over
-    ``mesh``: local scalar muls + Miller products per shard, all_gather
-    + GT-product reduction over "sp", one replicated final
-    exponentiation.  Arg order matches ``__graft_entry__``'s dryrun:
+    ``mesh``: local scalar muls + Miller products per shard, a gather
+    (``psum`` of slot stacks) + GT-product reduction over "sp", one
+    replicated final exponentiation.  Arg order matches
+    ``__graft_entry__``'s dryrun:
     ``(pk_aff, pk_inf, msg_aff, msg_inf, sig_aff, sig_inf, active,
     bits)``.
 
@@ -77,22 +77,13 @@ def build_sharded_verify(mesh):
     from lodestar_tpu.ops.bls12_381 import curve as cv, pairing as pr, tower as tw
     from lodestar_tpu.ops.bls12_381 import verify as dv
 
+    n_dev = mesh.shape[SHARD_AXIS]
+
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS),) * 8,
         out_specs=P(),
-        # Replication is by construction (every device all_gathers the
-        # same partials and reduces them identically) but 0.4.x
-        # check_rep / 0.6.x check_vma cannot infer it: psum outputs
-        # infer as replicated, all_gather outputs do NOT, and there is
-        # no cross-device *product* collective for the GT reduction, so
-        # the gather-then-reduce shape is forced and the check must be
-        # off.  tests/test_mesh_smoke.py carries the invariant
-        # dynamically (bit-equality vs the unsharded program) and
-        # tests/test_sharded_verify.py pins that enabling the check
-        # raises.
-        check_vma=False,  # lodelint: disable=replicated-escape — all_gather+reduce replication is correct by construction but not inferrable (no product collective); bit-equality tested in test_mesh_smoke.py
     )
     def sharded_verify(pk_aff, pk_inf, msg_aff, msg_inf, sig_aff, sig_inf, active, bits):
         pk_jac = cv.from_affine(cv.F1, pk_aff, pk_inf | ~active)
@@ -105,8 +96,8 @@ def build_sharded_verify(mesh):
         mask = active & ~rpk_inf & ~msg_inf
         local_f = dv.multi_miller_product(msg_aff, rpk_aff, mask)
 
-        sums = jax.lax.all_gather(local_sig_sum, "sp")
-        fs = jax.lax.all_gather(local_f, "sp")
+        sums = jax.lax.psum(_slot_stack(local_sig_sum, n_dev), SHARD_AXIS)
+        fs = jax.lax.psum(_slot_stack(local_f, n_dev), SHARD_AXIS)
         sig_sum = dv.jac_reduce_add(cv.F2, sums)
         f_msgs = dv.f12_reduce_mul(fs)
 
@@ -119,17 +110,13 @@ def build_sharded_verify(mesh):
     return sharded_verify
 
 
-def build_reduced_step(mesh, check_vma=False):
+def build_reduced_step(mesh):
     """Reduced sharded step over ``mesh``: the production curve kernels
     (mixed Jacobian arithmetic, branch-free double-and-add scalar mul)
     with the cross-shard Jacobian reduction made explicit — the pairing
     (Miller + final exp) is omitted so a cold compile fits a test
     budget.  Returns the affine sum ``((x, y), is_inf)`` so bit-equality
     against the unsharded execution compares canonical coordinates.
-
-    ``check_vma`` is exposed so tests can pin WHY the default is off:
-    on jax 0.4.x, enabling it raises at trace time because all_gather
-    outputs are never inferred replicated (see build_sharded_verify).
 
     @mesh: sp
     """
@@ -139,18 +126,19 @@ def build_reduced_step(mesh, check_vma=False):
     from lodestar_tpu.ops.bls12_381 import curve as cv, fp
     from lodestar_tpu.ops.bls12_381 import verify as dv
 
+    n_dev = mesh.shape[SHARD_AXIS]
+
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS),) * 4,
         out_specs=P(),
-        check_vma=check_vma,  # lodelint: disable=replicated-escape — defaults False: Jacobian sums need gather-then-reduce (no point-add collective), which 0.4.x check_rep cannot infer replicated; test_mesh_smoke.py pins bit-equality, test_sharded_verify.py pins the raise
     )
     def reduced_step(pk_aff, pk_inf, bits, active):
         pk_jac = cv.from_affine(cv.F1, pk_aff, pk_inf | ~active)
         rpk = cv.scalar_mul_bits(cv.F1, pk_jac, bits)
         local = dv.jac_reduce_add(cv.F1, rpk)
-        parts = jax.lax.all_gather(local, "sp")
+        parts = jax.lax.psum(_slot_stack(local, n_dev), SHARD_AXIS)
         total = dv.jac_reduce_add(cv.F1, parts)
         return cv.to_affine(cv.F1, total, fp.inv)
 

@@ -125,7 +125,7 @@ def _batch_inv(F, xs):
     def fwd(acc, x):
         return F.mul(acc, x), acc
 
-    init = _first_one(F, safe)
+    init = fp.vary_like(_first_one(F, safe), safe)
     total, pre = jax.lax.scan(fwd, init, safe)
     total_inv = _field_inv(F, total)
 

@@ -275,9 +275,9 @@ class TestWarm:
         assert warm.load_manifest(tmp_cache)["entries"] == {}
 
 
-class TestBenchWarmFirst:
-    """bench.py orders its stages warm-program-first off the manifest:
-    a cold flagship must not burn the budget ahead of a warm fallback."""
+class TestBenchPlatformGate:
+    """bench.py reports no chip number it did not take: off a TPU it
+    exits non-zero before any stage and prints no JSON line."""
 
     @staticmethod
     def _bench():
@@ -293,27 +293,25 @@ class TestBenchWarmFirst:
         spec.loader.exec_module(mod)
         return mod
 
-    def test_cold_flagship_yields_to_warm_fallback(self, tmp_path, monkeypatch):
-        bench = self._bench()
-        d = str(tmp_path / "cache")
-        monkeypatch.setenv("LODESTAR_TPU_JAX_CACHE", d)
-        envk = warm.environment_key()
-        manifest = {
-            "schema": warm.SCHEMA,
-            "entries": {"hashed/b1024": {**envk, "cache_keys": []}},
-        }
-        warm.save_manifest(manifest, d)
-        assert bench._warm_first((4096, 1024)) == (1024, 4096)
-        # both warm (or both cold): flagship keeps the lead
-        manifest["entries"]["hashed/b4096"] = {**envk, "cache_keys": []}
-        warm.save_manifest(manifest, d)
-        assert bench._warm_first((4096, 1024)) == (4096, 1024)
+    def test_device_info_names_platform_kind_count(self):
+        import jax
 
-    def test_no_manifest_keeps_order(self, tmp_path, monkeypatch):
-        bench = self._bench()
-        monkeypatch.setenv("LODESTAR_TPU_JAX_CACHE", str(tmp_path / "none"))
-        assert bench._warm_first((4096, 1024)) == (4096, 1024)
-        assert bench._warm_first((8,)) == (8,)
+        info = self._bench().device_info()
+        assert info == {
+            "platform": "cpu",
+            "kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()),
+        }
+
+    def test_require_tpu_refuses_cpu(self):
+        with pytest.raises(SystemExit, match="no TPU"):
+            self._bench().require_tpu()
+
+    def test_main_on_cpu_exits_nonzero_without_a_result_line(self, capsys):
+        with pytest.raises(SystemExit) as e:
+            self._bench().main()
+        assert e.value.code not in (0, None)
+        assert "{" not in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
@@ -327,10 +325,48 @@ class TestCacheConfig:
 
         assert jax.config.jax_compilation_cache_dir == tmp_cache
 
-    def test_configure_env_override(self, tmp_path, monkeypatch):
-        d = str(tmp_path / "envcache")
-        monkeypatch.setenv("LODESTAR_TPU_JAX_CACHE", d)
-        assert aot_cache.repo_cache_dir() == d
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_cache_dir_placed_by_jax_env_var(self, tmp_path, from_env):
+        """With $JAX_COMPILATION_CACHE_DIR set, configure() leaves jax's
+        cache where the variable puts it and writes nothing under the
+        checkout's fixed dir (stood in for by a tmp path); without it,
+        the dir is <checkout>/.jax_cache.  A fresh process: jax reads
+        the variable when it starts."""
+        import subprocess
+        import sys
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env_dir, fixed = str(tmp_path / "from_env"), str(tmp_path / "fixed")
+        code = f"""
+import json, os, jax, jax.numpy as jnp
+from lodestar_tpu.aot import cache
+want_default = cache.DEFAULT_CACHE_DIR
+cache.DEFAULT_CACHE_DIR = {fixed!r}
+got = cache.configure(min_compile_time_secs=0.0)
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(7)).block_until_ready()
+print(json.dumps([got, jax.config.jax_compilation_cache_dir, want_default]))
+"""
+        env = {
+            k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS")
+        }
+        env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+        if from_env:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        proc = subprocess.run(
+            [sys.executable, "-c", code], env=env, cwd=repo,
+            capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        got, jax_dir, want_default = json.loads(proc.stdout.splitlines()[-1])
+        assert want_default == os.path.join(repo, ".jax_cache")
+        if from_env:
+            assert got == jax_dir == env_dir
+            assert os.listdir(env_dir)
+            assert not os.path.exists(fixed)
+        else:
+            assert got == jax_dir == fixed
 
     def test_pin_cache_key_env(self):
         env = {"XLA_FLAGS": "--xla_whatever", "OTHER": "1"}
@@ -429,30 +465,6 @@ def _entry_file(cache_dir, key):
     paths = aot_cache.entry_paths(cache_dir, key)
     assert paths, f"no on-disk entry for {key}"
     return paths[0]
-
-
-class TestCacheGeneration:
-    def test_generation_salts_cache_dir(self, tmp_path, monkeypatch):
-        base = str(tmp_path / "cache")
-        monkeypatch.setenv("LODESTAR_TPU_JAX_CACHE", base)
-        monkeypatch.delenv("LODESTAR_TPU_CACHE_GENERATION", raising=False)
-        assert aot_cache.repo_cache_dir() == base
-        monkeypatch.setenv("LODESTAR_TPU_CACHE_GENERATION", "2")
-        assert aot_cache.repo_cache_dir() == os.path.join(base, "gen-2")
-        # bumping the generation never deletes the old dir's entries
-        os.makedirs(base, exist_ok=True)
-        open(os.path.join(base, "old-entry-cache"), "w").close()
-        assert aot_cache.repo_cache_dir() == os.path.join(base, "gen-2")
-        assert os.path.exists(os.path.join(base, "old-entry-cache"))
-
-    def test_generation_salts_opcache_env_key(self, monkeypatch):
-        from lodestar_tpu.ops.bls12_381 import opcache
-
-        monkeypatch.delenv("LODESTAR_TPU_CACHE_GENERATION", raising=False)
-        k1 = opcache._env_key()
-        monkeypatch.setenv("LODESTAR_TPU_CACHE_GENERATION", "2")
-        k2 = opcache._env_key()
-        assert k1 != k2
 
 
 class TestCacheSelfHeal:

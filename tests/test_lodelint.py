@@ -2406,7 +2406,7 @@ def test_collective_axis_negative_bound_by_local_mesh():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build():
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P("sp"))
@@ -2424,7 +2424,7 @@ def test_collective_axis_negative_helper_inherits_caller_axes():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def reduce_helper(x):
         return jax.lax.psum(x, "sp")
     def build():
@@ -2509,7 +2509,7 @@ def test_replicated_escape_positive_unreduced_output():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build():
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P())
@@ -2531,7 +2531,7 @@ def test_replicated_escape_negative_reduced_output():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build():
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P())
@@ -2549,7 +2549,7 @@ def test_replicated_escape_positive_check_vma_false_unreviewed():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build():
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P("sp"), check_vma=False)
@@ -2569,7 +2569,7 @@ def test_replicated_escape_negative_check_vma_false_reviewed():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build():
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P("sp"), check_vma=False)  # lodelint: disable=replicated-escape — gather+reduce not inferrable
@@ -2585,7 +2585,7 @@ def test_replicated_escape_check_vma_true_clean_dynamic_flagged():
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from lodestar_tpu.ops.bls12_381.sharded import shard_map
+    from jax import shard_map
     def build(flag):
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         @lambda f: shard_map(f, mesh=mesh, in_specs=P("sp"), out_specs=P("sp"), check_vma={})

@@ -35,54 +35,40 @@ def test_dryrun_multichip_8():
     assert proc.returncode == 0, proc.stderr.decode()[-2000:]
 
 
-def test_check_vma_cannot_be_enabled_on_this_jax():
-    """ISSUE 19 satellite: the extracted sharded program carries
-    check_vma=False under a reviewed lodelint suppression because this
-    jax's replication check (0.4.x check_rep) cannot infer that
-    gather-then-reduce outputs are replicated — there is no cross-device
-    Jacobian-add or GT-product collective, so the all_gather shape is
-    forced and psum-style inference never applies.  Pin the WHY: the
-    moment enabling the check stops raising here, flip the default to
-    True and drop the suppression.  Trace-time only — no XLA compile."""
+def test_checked_reduced_step_lowers_with_one_gather_collective():
+    """The reduced sharded step builds under jax.shard_map's default
+    check_vma=True: the gather is a psum of per-device slot stacks, so
+    its result is typed replicated and out_specs=P() type-checks.  The
+    lowered program reduces over the mesh with an all-reduce.  Bit
+    equality with the unchecked body is in test_mesh_smoke.py, which
+    already compiles this program.  Trace and lower only."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh
 
-    import pytest as _pytest
-
-    from lodestar_tpu.ops.bls12_381 import curve as cv, fp, sharded
+    from lodestar_tpu.ops.bls12_381 import curve as cv, sharded
 
     mesh = Mesh(np.array(jax.devices("cpu")[:2]), ("sp",))
-    B = 2
-    zero = jnp.zeros((30,), jnp.uint32)
-    pk_aff = (
-        jnp.broadcast_to(zero, (B, 30)),
-        jnp.broadcast_to(zero, (B, 30)),
+    pk_aff, pk_inf = cv.encode_g1_affine([None] * 4)
+    bits = cv.scalars_to_bits([3, 5, 7, 9], 4)
+    active = np.ones(4, bool)
+    text = (
+        jax.jit(sharded.build_reduced_step(mesh))
+        .lower(pk_aff, pk_inf, bits, active)
+        .as_text()
     )
-    pk_inf = jnp.ones(B, bool)
-    active = jnp.zeros(B, bool)
-    bits = cv.scalars_to_bits([1, 1], 2)
-    checked = sharded.build_reduced_step(mesh, check_vma=True)
-    with _pytest.raises(ValueError, match="replication|replicated"):
-        checked(pk_aff, pk_inf, bits, active)
+    assert "all_reduce" in text or "all-reduce" in text
 
 
-def test_reviewed_suppression_documents_why():
-    """The check_vma=False lines in ops/bls12_381/sharded.py must carry
-    the reviewed root suppression WITH a reason — lodelint's
-    replicated-escape rule enforces presence; this pins the reason
-    prose so it cannot degrade to a bare suppression."""
+def test_sharded_programs_keep_the_replication_check():
+    """No shard_map in ops/bls12_381/sharded.py turns check_vma off:
+    replication of every out_specs=P() result is checked by jax at
+    trace time, not asserted by a lint suppression."""
     import inspect
 
     from lodestar_tpu.ops.bls12_381 import sharded
 
     src = inspect.getsource(sharded)
-    suppressed = [
-        line
-        for line in src.splitlines()
-        if "check_vma=" in line and "lodelint: disable=replicated-escape" in line
-    ]
-    assert len(suppressed) == 2, suppressed
-    for line in suppressed:
-        assert "infer" in line, f"suppression lost its reason: {line}"
+    assert src.count("jax.shard_map,") == 2
+    assert "check_vma=" not in src
+    assert "lodelint: disable=replicated-escape" not in src

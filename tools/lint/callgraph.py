@@ -294,8 +294,7 @@ def _find_shard_call(dec: ast.AST) -> Optional[Tuple[str, ast.Call]]:
       ``@lambda f: shard_map(f, mesh=..., ...)``   (and jax.pmap forms)
       ``@shard_map(mesh=..., ...)`` / ``@jax.pmap(...)``
     Any dotted name ENDING in ``shard_map`` matches, so a repo-local
-    version-compat wrapper (ops/bls12_381/sharded.py's ``shard_map``)
-    binds axes exactly like the jax primitive it wraps.
+    wrapper binds axes exactly like the jax primitive it wraps.
     """
     def classify(call: ast.Call) -> Optional[Tuple[str, ast.Call]]:
         last = (dotted_name(call.func) or "").rsplit(".", 1)[-1]
@@ -1090,14 +1089,13 @@ class _Extractor(ast.NodeVisitor):
             if out is not None:
                 rec["out_replicated"] = _replicated_spec(out)
                 rec["out_line"] = out.lineno
-            for key in ("check_vma", "check_rep"):  # new / pre-0.6 kwarg name
-                if key in kwargs:
-                    v = kwargs[key]
-                    rec["check_vma_line"] = v.lineno
-                    if isinstance(v, ast.Constant) and isinstance(v.value, bool):
-                        rec["check_vma"] = v.value
-                    else:
-                        rec["check_vma"] = "dynamic"
+            if "check_vma" in kwargs:
+                v = kwargs["check_vma"]
+                rec["check_vma_line"] = v.lineno
+                if isinstance(v, ast.Constant) and isinstance(v.value, bool):
+                    rec["check_vma"] = v.value
+                else:
+                    rec["check_vma"] = "dynamic"
             return rec
         return None
 

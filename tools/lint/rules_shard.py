@@ -18,7 +18,7 @@ products, one shared final exponentiation) quietly depends on:
   (replicated) must be produced by a cross-axis collective on every
   return path (the bit-equality-vs-unsharded invariant
   tests/test_mesh_smoke.py checks dynamically, made static), and any
-  ``check_vma=False`` (``check_rep=False`` pre-0.6) needs a reviewed
+  ``check_vma=False`` needs a reviewed
   root suppression whose comment records WHY inference fails.
 * ``shard-divisibility`` — every AOT bucket rung that can feed a
   sharded program must divide evenly over every supported mesh size AND
@@ -185,12 +185,10 @@ class ReplicatedEscape(ProjectRule):
         "each device would return its LOCAL value and XLA silently "
         "keeps device 0's copy, the exact bug class "
         "tests/test_mesh_smoke.py's bit-equality check catches "
-        "dynamically.  Also flags check_vma=False (check_rep=False "
-        "pre-0.6): disabling JAX's varying-mesh-axes check requires a "
-        "reviewed `# lodelint: disable=replicated-escape` root "
-        "suppression whose comment records why inference fails "
-        "(e.g. all_gather-then-reduce formulations are replicated by "
-        "construction but not by 0.4.x check_rep inference)"
+        "dynamically.  Also flags check_vma=False: disabling JAX's "
+        "varying-mesh-axes check requires a reviewed "
+        "`# lodelint: disable=replicated-escape` root suppression whose "
+        "comment records why inference fails"
     )
 
     def check_project(self, project) -> List[Finding]:
