@@ -36,8 +36,13 @@ _aot_cache.configure()
 # green signal from `pytest -m fast` in minutes; `-m kernel` isolates the
 # compile-heavy XLA files; `-m e2e` the multi-process/network runs.
 # Assigned centrally by filename so per-file pytestmark lines (skipif
-# preset guards etc.) stay untouched.
+# preset guards etc.) stay untouched.  `slow` is set per test, never per
+# file: it marks a whole-pipeline XLA:CPU compile (a pairing, a verify
+# program, the full hash-to-curve) that alone takes over 300 s or 8 GB,
+# and `-m 'not slow'` leaves it out; its stages keep cheaper tests.
 # ---------------------------------------------------------------------------
+import fcntl  # noqa: E402
+
 import pytest  # noqa: E402
 
 _KERNEL_FILES = {
@@ -68,8 +73,9 @@ _E2E_FILES = {
     "test_official_vectors.py",
     "test_mock_el_process.py",
 }
-# correct but minutes-long single-process suites: neither fast nor e2e
-_SLOW_FILES = {
+# correct but minutes-long single-process suites: neither fast nor e2e;
+# they run unmarked
+_PLAIN_FILES = {
     "test_merge_forks.py",
     "test_beacon_chain.py",
     "test_dev_chain.py",
@@ -90,7 +96,7 @@ _SLOW_FILES = {
     "test_bls_pool_firehose.py",
 }
 # The quick tier is EXPLICIT opt-in (ADVICE r5 / lodelint fast-tier-
-# default): an unlisted file runs unmarked (slow-ish tier) and turns
+# default): an unlisted file runs unmarked (plain tier) and turns
 # tests/test_lodelint.py::test_every_test_file_is_tiered red until it is
 # placed in exactly one list above or below — a compile-heavy suite can
 # no longer slip into tier-1 by simply not being listed anywhere.
@@ -132,7 +138,19 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.e2e)
         elif name in _FAST_FILES:
             item.add_marker(pytest.mark.fast)
-        # anything else runs unmarked (slow-ish tier): an UNLISTED file can
+        # anything else runs unmarked (plain tier): an UNLISTED file can
         # never gain the fast marker.  tests/test_lodelint.py::
         # test_every_test_file_is_tiered fails (a normal red test, not an
         # aborted run) until the file is listed in exactly one tier.
+
+
+@pytest.fixture
+def heavy_compile(tmp_path_factory):
+    """Run the test holding an inter-process lock, so that at most one
+    of the tier's heaviest XLA:CPU compiles (about 5 GB each) runs at a
+    time across xdist workers (`--dist load` ignores `xdist_group`).
+    The lock file sits in the run's shared base temp directory."""
+    path = tmp_path_factory.getbasetemp().parent / "heavy-compile.lock"
+    with open(path, "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)
+        yield

@@ -11,6 +11,7 @@ come back stamped with the server's degradation tier.
 import asyncio
 import json
 import os
+import select
 import signal
 import subprocess
 import sys
@@ -49,6 +50,9 @@ def sidecar_process():
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
     )
     try:
+        # a child that hangs silently must fail the test, not stall it
+        ready, _, _ = select.select([proc.stdout], [], [], 120)
+        assert ready, "sidecar announced no port in 120 s"
         line = proc.stdout.readline().decode()
         assert line, "sidecar died before announcing its port"
         yield json.loads(line)["url"]

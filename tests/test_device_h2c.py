@@ -2,6 +2,10 @@
 
 The oracle is pinned to the RFC 9380 vectors (test_bls_oracle.py), so
 bit-equality here transitively pins the device pipeline to the RFC.
+
+The whole pipeline, ``hash_to_g2_from_fields``, takes XLA:CPU over 8 GB
+to compile, so its test is `slow`.  Its stages are tested alone in tier 1:
+``map_to_curve_g2``, and clear_cofactor's [|x|]-multiplication and psi.
 """
 import numpy as np
 import pytest
@@ -9,7 +13,8 @@ import pytest
 import jax
 
 from lodestar_tpu.crypto.bls import hash_to_curve as oh2c
-from lodestar_tpu.crypto.bls.curve import g2
+from lodestar_tpu.crypto.bls.curve import g2, psi
+from lodestar_tpu.crypto.bls.fields import ABS_X
 from lodestar_tpu.ops.bls12_381 import curve as cv, fp, h2c, tower as tw, verify as dv
 
 
@@ -32,6 +37,7 @@ def _jac_to_affine_int(jac):
     return g2.to_affine((x, y, z))
 
 
+@pytest.mark.usefixtures("heavy_compile")  # ~4.7 GB to compile
 def test_map_to_curve_matches_oracle():
     msgs = [bytes([i]) * 32 for i in range(3)]
     us = [u for m in msgs for u in oh2c.hash_to_field_fp2(m, 2)]
@@ -46,6 +52,47 @@ def test_map_to_curve_matches_oracle():
         assert got == exp, i
 
 
+@pytest.fixture(scope="module")
+def cofactor_inputs():
+    """Q0 + Q1 of two messages, the point clear_cofactor is handed
+    (Jacobian, Z != 1): oracle points and the device batch."""
+    pts = []
+    for m in (b"\x21" * 32, b"\x42" * 32):
+        q0, q1 = (g2.from_affine(oh2c.map_to_curve_g2(u)) for u in oh2c.hash_to_field_fp2(m, 2))
+        pts.append(g2.add_pts(q0, q1))
+    jac = jax.tree.map(
+        lambda *xs: np.stack(xs),
+        *[tuple(tw.encode_fp2(c) for c in pt) for pt in pts],
+    )
+    return pts, jac
+
+
+def _lane_affine(out, i):
+    return _jac_to_affine_int(jax.tree.map(lambda t: np.asarray(t)[i], out))
+
+
+@pytest.mark.usefixtures("heavy_compile")  # ~5.3 GB to compile
+def test_mul_abs_x_matches_oracle(cofactor_inputs):
+    """[|x|]P, the scalar-mul scan clear_cofactor runs twice.  The whole
+    clear_cofactor program (over 8 GB) is covered by the slow test of
+    hash_to_g2_from_fields below."""
+    pts, jac = cofactor_inputs
+    out = jax.jit(h2c._mul_abs_x)(jac)
+    for i, pt in enumerate(pts):
+        assert _lane_affine(out, i) == g2.to_affine(g2.mul_scalar(pt, ABS_X)), i
+
+
+def test_psi_matches_oracle(cofactor_inputs):
+    """clear_cofactor's inversion-free psi and psi^2 against the
+    oracle's affine psi."""
+    pts, jac = cofactor_inputs
+    once, twice = jax.jit(lambda p: (h2c._psi(p), h2c._psi(h2c._psi(p))))(jac)
+    for i, pt in enumerate(pts):
+        assert _lane_affine(once, i) == g2.to_affine(psi(pt)), i
+        assert _lane_affine(twice, i) == g2.to_affine(psi(psi(pt))), i
+
+
+@pytest.mark.slow  # the whole pipeline: over 8 GB to compile
 def test_hash_to_g2_from_fields_matches_oracle():
     msgs = [bytes([7 + i]) * 32 for i in range(4)]
     u0, u1 = h2c.encode_field_draws(msgs, 4)
@@ -55,13 +102,7 @@ def test_hash_to_g2_from_fields_matches_oracle():
         assert _jac_to_affine_int(lane) == g2.to_affine(oh2c.hash_to_g2(m)), i
 
 
-@pytest.mark.skipif(
-    __import__("os").environ.get("LODESTAR_TPU_SLOW_TESTS") != "1",
-    reason="the full hashed-verify kernel takes ~50 min to compile on "
-    "XLA:CPU (1-core host); its correctness gates run on real TPU in "
-    "every bench.py stage, and the map/hash differential tests above "
-    "cover the h2c math here — gate behind LODESTAR_TPU_SLOW_TESTS=1",
-)
+@pytest.mark.slow  # the whole hashed-verify program
 def test_verify_signature_sets_hashed():
     from lodestar_tpu.crypto.bls import api
     from lodestar_tpu.ops.bls12_381 import verify as dvv

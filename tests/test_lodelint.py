@@ -53,7 +53,7 @@ def test_every_test_file_is_tiered():
     tiers = {
         "_KERNEL_FILES": cf._KERNEL_FILES,
         "_E2E_FILES": cf._E2E_FILES,
-        "_SLOW_FILES": cf._SLOW_FILES,
+        "_PLAIN_FILES": cf._PLAIN_FILES,
         "_FAST_FILES": cf._FAST_FILES,
     }
     listed = [f for names in tiers.values() for f in names]

@@ -9,6 +9,7 @@ nothing is shared in-process, every byte crosses HTTP.
 import asyncio
 import json
 import os
+import select
 import signal
 import subprocess
 import sys
@@ -45,6 +46,9 @@ def el_process(tmp_path):
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
     )
     try:
+        # a child that hangs silently must fail the test, not stall it
+        ready, _, _ = select.select([proc.stdout], [], [], 120)
+        assert ready, "mock EL server announced no port in 120 s"
         line = proc.stdout.readline().decode()
         assert line, "mock EL server died before announcing its port"
         yield json.loads(line)["url"]

@@ -7,20 +7,13 @@ Runs the driver's dryrun entry in-process semantics: the same
 8-device virtual CPU mesh.  Shares its XLA cache entry with the driver's
 run, so after the first compile this is cheap.
 """
-import os
 import subprocess
 import sys
 
 import pytest
 
 
-@pytest.mark.skipif(
-    os.environ.get("LODESTAR_TPU_SLOW_TESTS") != "1",
-    reason="cold XLA:CPU compile of the sharded program takes ~40 min on a "
-    "1-core host; the driver runs the same dryrun_multichip entry itself "
-    "every round (MULTICHIP_r*.json), so the suite gates this behind "
-    "LODESTAR_TPU_SLOW_TESTS=1",
-)
+@pytest.mark.slow  # cold XLA:CPU compile of the whole sharded program
 def test_dryrun_multichip_8():
     proc = subprocess.run(
         [

@@ -8,6 +8,9 @@ regression oracle (generation and verification share the
 operation_specs table but serialize through the full SSZ round trip and
 re-execute the state transition from decoded bytes); independent
 evidence lives in tests/test_external_vectors.py and the KAT suites.
+
+Each suite is read by one test, so each test generates only the suites
+it reads: a run builds every suite once, whichever workers run the tests.
 """
 import os
 
@@ -34,19 +37,14 @@ pytestmark = [
 FORKS = fx.ALL_FORKS
 
 
-@pytest.fixture(scope="module")
-def gen_root(tmp_path_factory):
-    root = str(tmp_path_factory.mktemp("spec_fixtures"))
-    fx.generate_all(root)
-    return root
-
-
 def _suite_root(gen_root, fork, runner, handler):
     return os.path.join(gen_root, fork.value, runner, handler, "pyspec_tests")
 
 
 @pytest.mark.parametrize("fork", FORKS, ids=[f.value for f in FORKS])
-def test_operations_all_handlers(gen_root, fork):
+def test_operations_all_handlers(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_operations(gen_root, fork)
     cfg = fx.config_for(fork)
     specs = fx.operation_specs(fork)
     ran = 0
@@ -67,7 +65,9 @@ def test_operations_all_handlers(gen_root, fork):
 
 
 @pytest.mark.parametrize("fork", FORKS, ids=[f.value for f in FORKS])
-def test_sanity(gen_root, fork):
+def test_sanity(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_sanity(gen_root, fork)
     cfg = fx.config_for(fork)
     run_directory_spec_test(
         _suite_root(gen_root, fork, "sanity", "slots"),
@@ -85,7 +85,9 @@ def test_sanity(gen_root, fork):
     "fork", [f for f in FORKS if f is not ForkName.phase0],
     ids=[f.value for f in FORKS if f is not ForkName.phase0],
 )
-def test_fork_upgrade(gen_root, fork):
+def test_fork_upgrade(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_fork_upgrade(gen_root, fork)
     fn = fx.upgrade_ladder()[fork]
     pre_fork = FORKS[FORKS.index(fork) - 1]
     cfg = fx.config_for(pre_fork)
@@ -101,7 +103,9 @@ def test_fork_upgrade(gen_root, fork):
     [f for f in FORKS if FORK_SEQ[f] >= FORK_SEQ[ForkName.altair]],
     ids=[f.value for f in FORKS if FORK_SEQ[f] >= FORK_SEQ[ForkName.altair]],
 )
-def test_rewards(gen_root, fork):
+def test_rewards(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_rewards(gen_root, fork)
     cfg = fx.config_for(fork)
     run_directory_spec_test(
         _suite_root(gen_root, fork, "rewards", "basic"),
@@ -114,7 +118,9 @@ def test_rewards(gen_root, fork):
 @pytest.mark.parametrize(
     "fork", [ForkName.phase0, FORKS[-1]], ids=["phase0", FORKS[-1].value]
 )
-def test_finality(gen_root, fork):
+def test_finality(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_finality(gen_root, fork)
     cfg = fx.config_for(fork)
     run_directory_spec_test(
         _suite_root(gen_root, fork, "finality", "finality"),
@@ -126,7 +132,9 @@ def test_finality(gen_root, fork):
 @pytest.mark.parametrize(
     "fork", [ForkName.phase0, FORKS[-1]], ids=["phase0", FORKS[-1].value]
 )
-def test_fork_choice(gen_root, fork):
+def test_fork_choice(tmp_path, fork):
+    gen_root = str(tmp_path)
+    fx.gen_fork_choice(gen_root, fork)
     cfg = fx.config_for(fork)
     run_directory_spec_test(
         _suite_root(gen_root, fork, "fork_choice", "on_block"),

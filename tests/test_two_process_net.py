@@ -12,9 +12,11 @@ processes (plus one `validator` driving node A) on localhost:
 """
 import json
 import os
+import queue
 import signal
 import subprocess
 import sys
+import threading
 import time
 
 import pytest
@@ -43,7 +45,9 @@ pytestmark = pytest.mark.skipif(
 
 
 def _spawn(args, env):
-    return subprocess.Popen(
+    """Start a node; a daemon thread moves its stdout lines onto
+    ``proc.lines`` ("" at EOF), so every read below can time out."""
+    proc = subprocess.Popen(
         [sys.executable, "-m", "lodestar_tpu.cli.main", *args],
         stdout=subprocess.PIPE,
         stderr=subprocess.DEVNULL,
@@ -51,21 +55,30 @@ def _spawn(args, env):
         env=env,
         text=True,
     )
+    proc.lines = queue.Queue()
+
+    def pump():
+        for line in proc.stdout:
+            proc.lines.put(line)
+        proc.lines.put("")
+
+    threading.Thread(target=pump, daemon=True).start()
+    return proc
 
 
 def _read_until(proc, pred, timeout_s, sink):
     deadline = time.time() + timeout_s
-    while time.time() < deadline:
-        line = proc.stdout.readline()
+    while True:
+        try:
+            line = proc.lines.get(timeout=max(0.0, deadline - time.time()))
+        except queue.Empty:
+            raise AssertionError(f"timeout; last lines: {sink[-8:]}")
         if not line:
-            if proc.poll() is not None:
-                raise AssertionError(f"process exited rc={proc.returncode}")
-            continue
+            raise AssertionError(f"process exited rc={proc.wait(timeout=10)}")
         sink.append(line.strip())
         val = pred(line.strip())
         if val is not None:
             return val
-    raise AssertionError(f"timeout; last lines: {sink[-8:]}")
 
 
 def test_two_beacon_processes_discover_sync_and_gossip():
@@ -151,14 +164,9 @@ def test_two_beacon_processes_discover_sync_and_gossip():
             return None
 
         # drain A's output in parallel while polling B
-        import threading
-
         def drain_a():
-            try:
-                for line in a.stdout:
-                    a_log.append(line.strip())
-            except Exception:
-                pass
+            while line := a.lines.get():
+                a_log.append(line.strip())
 
         t = threading.Thread(target=drain_a, daemon=True)
         t.start()
