@@ -114,9 +114,11 @@ def install_cache_spy(
 ) -> None:
     """Wrap the persistent-cache read/write path.  ``callback`` (if
     given) receives (kind, cache_key, seconds) with kind in
-    {"hit", "miss", "put"}; seconds is the stored/observed compile time
-    (0.0 on miss).  Idempotent: the wrappers install once per process,
-    callbacks accumulate."""
+    {"hit", "miss", "put", "load_error"}; seconds is the stored/observed
+    compile time (0.0 on miss).  The executable store
+    (``aot/exec_store.py``) reports through the same callbacks with the
+    ``exec_*`` kinds.  Idempotent: the wrappers install once per
+    process, callbacks accumulate."""
     with _spy_lock:
         if callback is not None:
             _CALLBACKS.append(callback)
@@ -181,12 +183,12 @@ def install_cache_spy(
                     e,
                     quarantined or "<no file found>",
                 )
-                _emit("load_error", cache_key, 0.0)
+                emit("load_error", cache_key, 0.0)
                 return None, None
             if executable is not None:
-                _emit("hit", cache_key, float(compile_time or 0))
+                emit("hit", cache_key, float(compile_time or 0))
             else:
-                _emit("miss", cache_key, 0.0)
+                emit("miss", cache_key, 0.0)
             return executable, compile_time
 
         def spy_put(cache_key, *args, **kwargs):
@@ -204,7 +206,7 @@ def install_cache_spy(
             # is this put the rewrite half of a self-heal?  (load_error
             # was this key's last event before the recompile)
             healed = _KEYS.get(cache_key) == "load_error"
-            _emit("put", cache_key, seconds)
+            emit("put", cache_key, seconds)
             result = orig_put(cache_key, *args, **kwargs)
             if healed:
                 # re-stamp the warm manifest's entry hash: the healed
@@ -252,7 +254,8 @@ _STAT_KEY = {
 }
 
 
-def _emit(kind: str, cache_key: str, seconds: float) -> None:
+def emit(kind: str, cache_key: str, seconds: float) -> None:
+    """Count one cache event and hand it to every callback."""
     stat = _STAT_KEY.get(kind, kind)
     _STATS[stat] = _STATS.get(stat, 0) + 1
     _KEYS[cache_key] = kind

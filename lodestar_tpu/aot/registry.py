@@ -10,6 +10,9 @@ ever compiled.  This registry is now the single source of truth:
   kernel (verify.py's ``_jit_*`` attributes are these objects, and the
   lodelint ``unregistered-jit`` rule keeps any other module-scope
   ``jax.jit`` out of ``lodestar_tpu/``);
+- ``call(kernel, *args)`` is how every served call dispatches: through
+  the executable store, so a warm start loads each program without
+  tracing it;
 - ``registered_programs()`` enumerates the concrete (kernel, bucket)
   entries — with example avals — that ``python -m lodestar_tpu.aot
   warm`` compiles into the persistent cache.
@@ -23,10 +26,13 @@ direct-call bucket for belt-and-braces coverage.
 from __future__ import annotations
 
 import os
+import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
 from lodestar_tpu.ops.bls12_381 import buckets as bk
+
+from . import exec_store
 
 RAND_BITS = 64  # production random-coefficient width (bits)
 
@@ -77,6 +83,33 @@ def jitted(kernel: str):
         fns[kernel]
     )
     return wrapper
+
+
+# (kernel, treedef, avals) -> what serves that signature in this process
+_SERVED: Dict[tuple, Callable] = {}
+_first_dispatch = threading.Lock()
+
+
+def call(kernel: str, *args):
+    """Dispatch ``kernel`` on ``args``: the path of every served call.
+
+    The first call at an argument signature takes the program from the
+    executable store (``aot/exec_store.py``): loaded where the store
+    holds it, else compiled and written; one thread does so while the
+    others wait for it.  A later call adds a lookup on the leaves' avals
+    and never traces."""
+    treedef, avals = exec_store.signature(args)
+    signature = (kernel, treedef, avals)
+    fn = _SERVED.get(signature)
+    if fn is None:
+        with _first_dispatch:
+            fn = _SERVED.get(signature)
+            if fn is None:
+                name = f"jit_{ensure_kernels()[kernel].__name__}"
+                fn = _SERVED[signature] = exec_store.load_or_compile(
+                    name, jitted(kernel), args, treedef, avals
+                )
+    return fn(*args)
 
 
 @dataclass(frozen=True)

@@ -15,7 +15,10 @@ exactly the manifest — never the cache files themselves.  Nothing here
 ever deletes ``.jax_cache`` entries; stale entries are merely
 recompiled under their new keys.
 
-Each warmed program is additionally serialized to
+Each warmed program's compiled executable is saved to the executable
+store (``aot/exec_store.py``), which served calls load on a warm start;
+a program counts as warm only while its entry is there.  Each is also
+serialized to
 ``<cache>/export/<kernel>_b<bucket>.bin`` (portable StableHLO, usable
 for cross-process AOT loading); failures are recorded, not fatal.
 """
@@ -28,6 +31,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import cache as aot_cache
+from . import exec_store
 
 MANIFEST_NAME = "warm_manifest.json"
 SCHEMA = 2
@@ -41,22 +45,34 @@ SOURCE_DIRS = (
     "lodestar_tpu/crypto/bls",
     "lodestar_tpu/aot",
 )
+# the parent packages' __init__ files, which importing the kernels runs
+SOURCE_FILES = (
+    "lodestar_tpu/__init__.py",
+    "lodestar_tpu/crypto/__init__.py",
+    "lodestar_tpu/ops/__init__.py",
+)
 
 
-def source_fingerprint() -> str:
-    """sha256 over the kernel-relevant source tree (path + content)."""
-    h = hashlib.sha256()
+def source_files() -> List[str]:
+    """The fingerprinted sources, as paths relative to the repo root."""
+    out = list(SOURCE_FILES)
     for d in SOURCE_DIRS:
         root = os.path.join(_REPO_ROOT, d)
         for dirpath, dirnames, filenames in os.walk(root):
             dirnames[:] = sorted(x for x in dirnames if x != "__pycache__")
             for fn in sorted(filenames):
-                if not fn.endswith(".py"):
-                    continue
-                rel = os.path.relpath(os.path.join(dirpath, fn), _REPO_ROOT)
-                h.update(rel.encode())
-                with open(os.path.join(dirpath, fn), "rb") as fh:
-                    h.update(hashlib.sha256(fh.read()).digest())
+                if fn.endswith(".py"):
+                    out.append(os.path.relpath(os.path.join(dirpath, fn), _REPO_ROOT))
+    return out
+
+
+def source_fingerprint() -> str:
+    """sha256 over the kernel-relevant source tree (path + content)."""
+    h = hashlib.sha256()
+    for rel in source_files():
+        h.update(rel.encode())
+        with open(os.path.join(_REPO_ROOT, rel), "rb") as fh:
+            h.update(hashlib.sha256(fh.read()).digest())
     return h.hexdigest()
 
 
@@ -153,6 +169,8 @@ def program_state(
     # manifest freshness alone; captured keys are verified on disk
     if keys and not all(aot_cache.entry_exists(cache_dir, k) for k in keys):
         return "missing"
+    if not exec_store.holds(cache_dir, entry.get("executable")):
+        return "missing"
     if check_hashes and mismatched_entry_keys(entry, cache_dir):
         return "corrupt"
     return "warm"
@@ -198,13 +216,17 @@ def warm_program(prog, cache_dir: str, do_export: bool = True) -> Dict:
     aot_cache.install_cache_spy(_capture)
     try:
         t0 = time.monotonic()
-        lowered = prog.fn().lower(*prog.example_args())
+        args = prog.example_args()
+        lowered = prog.fn().lower(*args)
         lower_s = time.monotonic() - t0
         t1 = time.monotonic()
-        lowered.compile()
+        compiled = lowered.compile()
         compile_s = time.monotonic() - t1
     finally:
         aot_cache.remove_cache_spy_callback(_capture)
+    # the entry a served call loads (``registry.call``): without it the
+    # node's first start would still trace and lower the program
+    executable = exec_store.save(f"jit_{prog.fn_name()}", compiled, args)
     hit = any(kind == "hit" for kind in events.values())
     # content fingerprint of each entry file: ``--check`` compares these
     # so an entry that later rots on disk reports "corrupt", not "warm"
@@ -219,6 +241,7 @@ def warm_program(prog, cache_dir: str, do_export: bool = True) -> Dict:
         "cache_keys": sorted(events),
         "cache_hit": hit,
         "entry_sha256": entry_sha,
+        "executable": executable,
         "lower_s": round(lower_s, 3),
         "compile_s": round(compile_s, 3),
         "warmed_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),

@@ -292,6 +292,8 @@ class DeviceBlsVerifier:
             # poisoned persistent-cache entry: the spy quarantined it
             # and jax recompiled (aot/cache.py self-heal path)
             m.persistent_cache_load_errors.inc()
+        elif kind.startswith("exec_"):
+            m.executable_store_events.labels(kind=kind).inc()
 
     # ------------------------------------------------------------------
 

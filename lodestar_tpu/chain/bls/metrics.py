@@ -71,6 +71,14 @@ class BlsPoolMetrics:
             "Programs the persistent cache did not hold (cold compile)",
             registry=registry,
         )
+        self.executable_store_events = Counter(
+            f"{ns}_executable_store_events_total",
+            "Executable-store events (aot/exec_store.py) by kind: "
+            "exec_hit (loaded, nothing traced), exec_miss, exec_put, "
+            "exec_load_error (removed and recompiled)",
+            labelnames=("kind",),
+            registry=registry,
+        )
         self.warm_manifest_fresh = Gauge(
             f"{ns}_warm_manifest_fresh",
             "1 if every AOT-registered program was warm at pool start "

@@ -32,7 +32,7 @@ from jax._src.core import eval_jaxpr as _eval_jaxpr
 _CACHE: dict = {}
 
 
-def _env_key():
+def env_key():
     import os
 
     from . import fp
@@ -74,7 +74,7 @@ def cached(fn, static_argnums: tuple = ()):
             # carries; trace the op itself instead
             return fn(*args)
         try:
-            key = (fn, statics, treedef, tuple(avals), _env_key())
+            key = (fn, statics, treedef, tuple(avals), env_key())
             hash(key)
         except TypeError:
             return fn(*args)

@@ -356,7 +356,9 @@ from .buckets import BUCKETS as _BUCKETS, bucket_size  # noqa: F401,E402
 # The jit wrappers live in the AOT registry (lodestar_tpu/aot/registry.py)
 # — the single source of truth for every program the warm tool must
 # compile.  The module attributes below are THE registry objects, kept
-# under their historical names for call sites (bench.py, tests).
+# under their historical names for bench.py and the tests.  Served calls
+# dispatch through ``registry.call``, which loads each program from the
+# executable store on a warm start instead of tracing it.
 from lodestar_tpu.aot import registry as _aot_registry  # noqa: E402
 
 _aot_registry.register_kernels(
@@ -368,7 +370,6 @@ _aot_registry.register_kernels(
 
 _jit_batch = _aot_registry.jitted("batch")
 _jit_hashed = _aot_registry.jitted("hashed")
-_jit_each = _aot_registry.jitted("each")
 
 
 def _encode_pk_sig(sets, size: int):
@@ -482,9 +483,8 @@ def execute_batch(job: EncodedJob) -> bool:
     """Device execute stage for an encoded job: dispatch + sync."""
     if job.kind == "reject":
         return False
-    fn = _jit_hashed if job.kind == "hashed" else _jit_batch
     return bool(  # lodelint: disable=host-sync — API boundary: callers need a python bool
-        fn(*job.args)
+        _aot_registry.call(job.kind, *job.args)
     )
 
 
@@ -499,9 +499,6 @@ def verify_signature_sets_device(sets, rand=None) -> bool:
     encode_job + execute_batch in one call; the pool runs the two stages
     pipelined instead."""
     return execute_batch(encode_job(sets, rand=rand))
-
-
-_jit_fast_agg = _aot_registry.jitted("fast_agg")
 
 
 def fast_aggregate_verify_device(public_keys, message: bytes, signature) -> bool:
@@ -524,7 +521,8 @@ def fast_aggregate_verify_device(public_keys, message: bytes, signature) -> bool
     sig_aff, sig_inf = cv.encode_g2_affine([signature.point])
     squeeze = lambda t: jax.tree.map(lambda x: x[0], t)
     return bool(  # lodelint: disable=host-sync — API boundary: callers need a python bool
-        _jit_fast_agg(
+        _aot_registry.call(
+            "fast_agg",
             pk_aff,
             pk_inf,
             squeeze(msg_aff),
@@ -546,6 +544,8 @@ def verify_each_device(sets, bucket=None):
     size = bucket if bucket is not None else bucket_size(len(sets))
     assert size >= len(sets), f"bucket {size} < {len(sets)} sets"
     pk_aff, pk_inf, msg_aff, msg_inf, sig_aff, sig_inf, act = _encode_sets(sets, size)
-    out = _jit_each(pk_aff, pk_inf, msg_aff, msg_inf, sig_aff, sig_inf, act)
+    out = _aot_registry.call(
+        "each", pk_aff, pk_inf, msg_aff, msg_inf, sig_aff, sig_inf, act
+    )
     # API boundary: the per-set host bools leave the device here
     return [bool(x) for x in np.asarray(out)[: len(sets)]]  # lodelint: disable=host-sync

@@ -109,6 +109,7 @@ _FAST_FILES = {
     "test_dashboards.py",
     "test_db.py",
     "test_engine_http.py",
+    "test_exec_store.py",
     "test_eth1.py",
     "test_eth1_http.py",
     "test_faults.py",

@@ -397,11 +397,11 @@ print(json.dumps([got, jax.config.jax_compilation_cache_dir, want_default]))
         events = []
         cb = lambda *e: events.append(e)  # noqa: E731
         aot_cache.install_cache_spy(cb)
-        aot_cache._emit("hit", "k-spy-removal", 0.1)
+        aot_cache.emit("hit", "k-spy-removal", 0.1)
         assert events
         aot_cache.remove_cache_spy_callback(cb)
         n = len(events)
-        aot_cache._emit("hit", "k-spy-removal", 0.1)
+        aot_cache.emit("hit", "k-spy-removal", 0.1)
         assert len(events) == n
         # removing twice is a no-op, not an error
         aot_cache.remove_cache_spy_callback(cb)

@@ -1811,6 +1811,27 @@ def test_retrace_hazard_positive_inline_len_at_dispatch():
     assert all("len()-derived width" in f.message for f in fs)
 
 
+def test_retrace_hazard_served_dispatch_through_registry_call():
+    # served calls dispatch through registry.call(kernel, *args), not a
+    # jitted wrapper: a len()-derived width there is the same hazard, and
+    # a quantized one is not
+    src = """
+    from lodestar_tpu.aot import registry as _reg
+    from lodestar_tpu.ops.bls12_381 import buckets as bk
+    def raw(sets):
+        n = len(sets)
+        return _reg.call("k", sets, n)
+    def quantized(sets):
+        size = bk.bucket_size(len(sets))
+        return _reg.call("k", sets, size)
+    """
+    fs = lint(src, rule="retrace-hazard")
+    assert len(fs) == 1
+    assert fs[0].line == 6
+    assert "len()-derived width" in fs[0].message
+    assert fs[0].chain == ("lodestar_tpu/mod.py:6 raw [dispatches jitted program]",)
+
+
 def test_retrace_hazard_negative_tensor_args_at_dispatch():
     # tensor/encoded positional args at a dispatch site are NOT widths;
     # only len-provenance is judged there

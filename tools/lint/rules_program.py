@@ -36,6 +36,8 @@ from .effects import chain_for, root_site
 # where the rung geometry lives; parsed from the project summaries so the
 # rule updates itself when the bucket tables change
 _BUCKETS_MODULE = "lodestar_tpu.ops.bls12_381.buckets"
+# the registry function every served call dispatches a program through
+_SERVED_DISPATCH = "lodestar_tpu.aot.registry.call"
 # fallback for single-file fixtures that don't include the buckets module
 _DEFAULT_RUNGS = frozenset((4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048))
 _DEFAULT_STEP = 512
@@ -50,11 +52,16 @@ def _jit_connected(s: dict) -> bool:
     jit machinery: ones that mint ``registry.jitted()`` wrappers or
     import the bucket-rung module.  The DB layer's keyspace ``Bucket``
     enum and pallas limb ``width`` params reuse the words with entirely
-    different meanings — out of scope by construction."""
+    different meanings — out of scope by construction.  Importing the
+    registry whose ``call`` dispatches served programs connects a module
+    too."""
     if s.get("jit_wrappers"):
         return True
+    registry = _SERVED_DISPATCH.rsplit(".", 1)[0]
     for target in s.get("imports", {}).values():
         if target == _BUCKETS_MODULE or target.startswith(_BUCKETS_MODULE + "."):
+            return True
+        if target == registry:
             return True
     return False
 
@@ -237,11 +244,16 @@ class RetraceHazard(ProjectRule):
     def _dispatches(self, s: dict, fs: dict, env) -> List[dict]:
         own_wrappers = set(s.get("jit_wrappers", ()))
         aliases = set(fs.get("jit_aliases", ()))
+        imports = s.get("imports", {})
         out = []
         for c in fs.get("calls", ()):
             target = c["target"]
             last = target.rsplit(".", 1)[-1]
-            if "." in target:
+            head, _, rest = target.partition(".")
+            resolved = imports.get(head, head) + ("." + rest if rest else "")
+            if resolved == _SERVED_DISPATCH:
+                out.append(c)
+            elif "." in target:
                 if last in env.jit_wrappers:
                     out.append(c)
             elif last in own_wrappers or last in aliases:
